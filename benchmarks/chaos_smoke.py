@@ -98,7 +98,9 @@ def _timed_sweep(plan=None, spec=_SPEC):
 
 
 def _cli_env():
-    env = dict(os.environ)
+    # the child sweeps are host-side gates, and this process has already
+    # touched JAX: on a TPU host it holds the chip, so children take the CPU
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     src = str(pathlib.Path(__file__).resolve().parents[1] / "src")
     env["PYTHONPATH"] = (src + os.pathsep + env["PYTHONPATH"]
                          if env.get("PYTHONPATH") else src)

@@ -247,7 +247,9 @@ def main(argv=None) -> int:
         # only the producer side (sink thread + serialization + send) —
         # in deployment the collector is a service, not a thread of the
         # simulator.
-        env = dict(os.environ)
+        # this process has touched JAX (and holds the chip on a TPU host):
+        # the consumer is host-side, so it runs JAX on the CPU
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
         src = str(pathlib.Path(__file__).resolve().parents[1] / "src")
         env["PYTHONPATH"] = (src + os.pathsep + env["PYTHONPATH"]
                              if env.get("PYTHONPATH") else src)

@@ -78,6 +78,8 @@ from repro.cluster.experiment import (ExperimentConfig, atlas_base_name,
                                       run_scheduler)
 from repro.cluster.scenarios import SCENARIOS, WORKLOAD_SHAPES, make_spec
 from repro.core.predictor import TaskPredictor
+from repro.ml.models import forest_shape
+from repro.util import enable_compile_cache
 
 # metrics reported in the ranking tables (subset of Simulator.metrics keys)
 TABLE_METRICS = ("pct_tasks_failed", "pct_jobs_failed", "job_exec_time",
@@ -287,6 +289,27 @@ def _run_atlas_cell(args):
             metrics.get("obs"))
 
 
+# Largest flush (rows) whose kernel shapes are compiled before a device-
+# scored ATLAS wave starts; a larger flush compiles its shape when it comes.
+WARM_FLUSH_ROWS = 16384
+
+
+def _serving_impl(wave2) -> str:
+    """Flush backend of the ATLAS wave's broker (``ml.forest.serving_impl``).
+    When that is the device kernel, every shape the wave's flushes can take
+    is compiled before any client starts, so no request waits on the
+    compiler (a wait long enough trips a client's request timeout)."""
+    from repro.ml.forest import serving_impl
+    impl = serving_impl()
+    shape = forest_shape(wave2[0][1].algo) if wave2 else None
+    if impl != "numpy" and shape is not None:
+        from repro.cluster.telemetry import N_FEATURES
+        from repro.kernels.forest import warmup_grouped
+        # each cell brings a map and a reduce model
+        warmup_grouped(2 * len(wave2), *shape, N_FEATURES, WARM_FLUSH_ROWS)
+    return impl
+
+
 def _run_atlas_wave_brokered(wave2, registry_dir, workers=None,
                              obs_dir=None):
     """Run every ATLAS cell concurrently as a client of one shared
@@ -297,7 +320,7 @@ def _run_atlas_wave_brokered(wave2, registry_dir, workers=None,
 
     from repro.online.broker import BrokerPredictor, PredictionBroker
 
-    broker = PredictionBroker(impl="numpy")
+    broker = PredictionBroker(impl=_serving_impl(wave2))
     broker_obs = None
     if obs_dir is not None:
         from repro.obs import BrokerObserver, NDJSONSink
@@ -366,7 +389,7 @@ def _run_atlas_wave_async(wave2, registry_dir, workers=None, obs_dir=None,
     from repro.online.broker import BrokerPredictor
     from repro.online.server import AsyncBroker, BrokerClient
 
-    server = AsyncBroker(impl="numpy", policy="barrier")
+    server = AsyncBroker(impl=_serving_impl(wave2), policy="barrier")
     broker_obs = None
     if obs_dir is not None:
         from repro.obs import BrokerObserver, NDJSONSink
@@ -431,6 +454,7 @@ def _run_atlas_wave_async(wave2, registry_dir, workers=None, obs_dir=None,
                 p.n_fallbacks for p in predictors)
             fault_stats["fallback_rows"] = sum(
                 p.n_fallback_rows for p in predictors)
+            fault_stats["device_flushes"] = server.n_device_flushes
     finally:
         server.stop()
     if broker_obs is not None:
@@ -450,6 +474,11 @@ class _SerialExecutor:
         return False
 
 
+def _cpu_worker():
+    import jax
+    jax.config.update("jax_platforms", "cpu")
+
+
 def _make_executor(kind: str, workers: int | None):
     if kind in ("serial", "broker", "async"):
         # "broker"/"async" batch only the ATLAS wave (threads sharing one
@@ -459,10 +488,13 @@ def _make_executor(kind: str, workers: int | None):
         return concurrent.futures.ThreadPoolExecutor(max_workers=workers)
     if kind == "process":
         # spawn, not fork: workers get a fresh JAX runtime (fork after backend
-        # init deadlocks) and behave identically across platforms
+        # init deadlocks) and behave identically across platforms.  Workers
+        # run JAX on the CPU: a chip belongs to one process, and on a TPU
+        # host that is the parent, never a pool worker
         ctx = multiprocessing.get_context("spawn")
         return concurrent.futures.ProcessPoolExecutor(
-            max_workers=workers or os.cpu_count(), mp_context=ctx)
+            max_workers=workers or os.cpu_count(), mp_context=ctx,
+            initializer=_cpu_worker)
     raise ValueError(
         f"unknown executor {kind!r} (process|thread|serial|broker|async)")
 
@@ -996,6 +1028,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     args = build_parser().parse_args(argv)
     if args.list_scenarios:
         for name, sc in sorted(SCENARIOS.items()):

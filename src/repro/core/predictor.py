@@ -95,7 +95,8 @@ class TaskPredictor:
 
         Forest-family models are pinned to the numpy mirror whatever the batch
         size: ``predict_proba`` would auto-route >SMALL_BATCH batches onto the
-        XLA kernel, whose tree mean rounds differently at the last ulp, and
+        kernel path, whose CPU reference (``xla``) rounds its tree mean
+        differently at the last ulp, and
         scheduler decisions must not depend on candidate-set size or executor
         (the broker memoises these exact floats).  Training/CV paths keep the
         size-dispatched ``forest_predict`` route."""

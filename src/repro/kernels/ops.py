@@ -6,23 +6,26 @@ Every op takes ``impl``:
   "pallas"     compiled Pallas TPU kernel — the production path on real hardware.
   "interpret"  Pallas kernel body interpreted on CPU — correctness tests.
 
-The default comes from ``repro.kernels.ops.DEFAULT_IMPL`` (env: REPRO_KERNEL_IMPL)
-so tests can flip the whole model zoo onto interpret-mode kernels.
+``impl=None`` picks from the backend (``default_impl``): the compiled kernel
+on a TPU, the ``xla`` reference everywhere else.
 """
 
 from __future__ import annotations
 
-import os
-
+import jax
 
 from repro.kernels import ref
 
-DEFAULT_IMPL = os.environ.get("REPRO_KERNEL_IMPL", "xla")
 _VALID = ("xla", "pallas", "interpret")
 
 
+def default_impl() -> str:
+    """The kernel path for this process's default JAX backend."""
+    return "pallas" if jax.default_backend() == "tpu" else "xla"
+
+
 def _resolve(impl: str | None) -> str:
-    impl = impl or DEFAULT_IMPL
+    impl = impl or default_impl()
     if impl not in _VALID:
         raise ValueError(f"impl must be one of {_VALID}, got {impl!r}")
     return impl

@@ -65,8 +65,6 @@ def run_cell(arch_id: str, shape_id: str, *, multi_pod: bool = False,
 
             mem = compiled.memory_analysis()
             cost = compiled.cost_analysis()
-            if isinstance(cost, list):  # older jaxlib: [dict] per partition
-                cost = cost[0]
             hlo = compiled.as_text()
             # loop-aware accounting: XLA's cost_analysis counts while bodies once,
             # which undercounts scan-over-layers models by ~n_layers (see

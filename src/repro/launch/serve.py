@@ -15,9 +15,11 @@ import numpy as np
 
 from repro.configs import ARCH_IDS, get_arch, smoke_reduce
 from repro.models import get_model
+from repro.util import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=list(ARCH_IDS))
     ap.add_argument("--batch", type=int, default=4)

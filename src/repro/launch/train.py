@@ -14,9 +14,11 @@ import pathlib
 from repro.configs import ARCH_IDS, get_arch, smoke_reduce
 from repro.data import DataConfig
 from repro.runtime import ElasticTrainer, RuntimeConfig
+from repro.util import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=list(ARCH_IDS))
     ap.add_argument("--steps", type=int, default=100)

@@ -51,6 +51,13 @@ def make_bins(X: np.ndarray, n_bins: int) -> np.ndarray:
     return out
 
 
+def _exact_dot(a, b):
+    """f32 matmul at full precision: the TPU's default rounds operands to
+    bf16, which would change the split sums (and so the chosen splits)
+    relative to a fit on the CPU."""
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
 @functools.partial(jax.jit, static_argnames=("n_leaves", "criterion"))
 def _best_split(bits, w, wy, wyy, leaf, *, n_leaves: int, criterion: str):
     """One oblivious level for a batch of trees.
@@ -66,10 +73,10 @@ def _best_split(bits, w, wy, wyy, leaf, *, n_leaves: int, criterion: str):
         wt, wyt, wyyt, lt = args
         oh = jax.nn.one_hot(lt, L, dtype=jnp.float32)          # (N, L)
         stacked = jnp.stack([wt, wyt, wyyt], axis=1)           # (N, 3)
-        tot = oh.T @ stacked                                   # (L, 3)
-        lw = (oh * wt[:, None]).T @ bits                       # (L, FQ)
-        ly = (oh * wyt[:, None]).T @ bits
-        lyy = (oh * wyyt[:, None]).T @ bits
+        tot = _exact_dot(oh.T, stacked)                        # (L, 3)
+        lw = _exact_dot((oh * wt[:, None]).T, bits)            # (L, FQ)
+        ly = _exact_dot((oh * wyt[:, None]).T, bits)
+        lyy = _exact_dot((oh * wyyt[:, None]).T, bits)
         rw = tot[:, 0:1] - lw
         ry = tot[:, 1:2] - ly
         ryy = tot[:, 2:3] - lyy
@@ -98,8 +105,8 @@ def _leaf_values(w, wy, leaf, *, n_leaves: int):
     def per_tree(args):
         wt, wyt, lt = args
         oh = jax.nn.one_hot(lt, n_leaves, dtype=jnp.float32)
-        sw = oh.T @ wt
-        sy = oh.T @ wyt
+        sw = _exact_dot(oh.T, wt)
+        sy = _exact_dot(oh.T, wyt)
         return sy / jnp.maximum(sw, 1e-9)
     return jax.lax.map(per_tree, (w, wy, leaf))
 
@@ -222,6 +229,13 @@ def forest_predict_np(params: ForestParams, X: np.ndarray,
 GROUPED_KERNEL_ROWS = 512
 
 
+def serving_impl() -> str:
+    """Flush backend of the serving brokers: the grouped Pallas kernel when
+    the default JAX backend is a TPU, the numpy mirror elsewhere.  The kernel
+    reproduces the mirror's bits, so the choice moves work, not results."""
+    return "pallas" if jax.default_backend() == "tpu" else "numpy"
+
+
 @dataclasses.dataclass
 class PackedForests:
     """Many forests packed into one padded block-diagonal tensor layout.
@@ -326,10 +340,12 @@ def forest_predict_grouped(groups, *, impl: str = "numpy") -> tuple[list, int]:
     ForestParams object share one segment, so a saturated flush of many
     requests against one model costs one model's worth of trees.
 
-    impl: "numpy" (default — strict bit-parity), "auto" (numpy below
-    ``GROUPED_KERNEL_ROWS`` total rows, the XLA/Pallas grouped kernel above),
-    or an explicit kernel impl ("xla"/"pallas"/"interpret") to force the
-    packed device pass (kernel tree means round differently at the last ulp).
+    impl: "numpy" (default), "auto" (numpy below ``GROUPED_KERNEL_ROWS``
+    total rows, the backend's grouped kernel path above — see
+    ``kernels.ops``), or an explicit kernel impl ("xla"/"pallas"/
+    "interpret") to force the packed pass.  The Pallas kernel (compiled or
+    interpreted) is bit-identical to the numpy mirror; the "xla" reference
+    may round its tree mean differently at the last ulp.
     """
     outs: list = [None] * len(groups)
     by_params: dict[int, list[int]] = {}      # id(params) -> group indices

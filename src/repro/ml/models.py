@@ -96,11 +96,13 @@ class GLM(BaseModel):
 class Tree(BaseModel):
     name = "Tree"
     criterion = "var"
+    n_trees = 1
     depth = 6
 
     def fit(self, X, y):
         self.params = fit_oblivious_forest(
-            X, y, n_trees=1, depth=self.depth, n_bins=16, bootstrap=False,
+            X, y, n_trees=self.n_trees, depth=self.depth, n_bins=16,
+            bootstrap=False,
             criterion=self.criterion)
         return self
 
@@ -220,6 +222,15 @@ class NeuralNet(BaseModel):
         h = np.tanh(Xs @ np.asarray(p["w1"]) + np.asarray(p["b1"]))
         z = (h @ np.asarray(p["w2"]) + np.asarray(p["b2"]))[:, 0]
         return 1.0 / (1.0 + np.exp(-z))
+
+
+def forest_shape(algo: str) -> tuple[int, int] | None:
+    """(n_trees, depth) of a single-forest algo (Tree / CTree / R.F.) — the
+    shape its models take in a grouped flush — else None."""
+    model = ALL_MODELS[algo]()
+    if isinstance(model, (Tree, RandomForest)):
+        return model.n_trees, model.depth
+    return None
 
 
 ALL_MODELS = {

@@ -52,6 +52,7 @@ import numpy as np
 import repro
 from repro.core.predictor import TaskPredictor
 from repro.online.broker import PredictionBroker
+from repro.util import enable_compile_cache
 
 # deterministic request-size mix mimicking the scheduler's demand: mostly
 # single-proposal p_success rows, periodically a candidate-set p_success_nodes
@@ -611,6 +612,7 @@ def run_bench_sizes(fleet_sizes, **kw) -> dict:
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(
         prog="python -m repro.online.bench",
         description="Broker load generator: replay fleet decision streams")

@@ -44,11 +44,12 @@ and scalar calls all produce bit-identical floats for the forest family
 (Tree / CTree / R.F.) on any fleet size.  Other algos score unfused via their
 own ``predict_proba``.
 
-``impl`` selects the flush backend: ``"numpy"`` (default — strict parity via
-the block-diagonal numpy pass), ``"auto"`` (size-dispatched: fat flushes route
-to the grouped XLA/Pallas forest kernel, trading last-ulp parity for MXU
-throughput), or an explicit kernel impl (``"xla"`` / ``"pallas"`` /
-``"interpret"``)."""
+``impl`` selects the flush backend: ``"numpy"`` (default — the block-diagonal
+numpy pass), ``"auto"`` (size-dispatched: fat flushes route to the backend's
+grouped forest kernel path), or an explicit kernel impl (``"xla"`` /
+``"pallas"`` / ``"interpret"``).  The Pallas kernel reproduces the numpy
+pass bit for bit; the fleet picks ``"pallas"`` on a TPU and ``"numpy"``
+elsewhere (``ml.forest.serving_impl``)."""
 
 from __future__ import annotations
 

@@ -80,6 +80,7 @@ import time
 
 import numpy as np
 
+from repro.kernels import forest as forest_kernels
 from repro.online.broker import score_groups
 from repro.online.faults import (FaultInjector, PredictorUnavailableError,
                                  backoff_delay)
@@ -175,6 +176,7 @@ class AsyncBroker:
         self.n_telemetry_frames = 0
         self.n_replays = 0               # cached replies resent to retries
         self.n_dup_requests = 0          # retransmits of in-flight requests
+        self.n_device_flushes = 0        # flushes scored by the device kernel
 
     # ------------------------------------------------------------ lifecycle
     def start(self) -> "AsyncBroker":
@@ -500,12 +502,14 @@ class AsyncBroker:
         self._drain.set()
         flat = [g for req in batch for g in req.groups]
         t0 = time.perf_counter()
+        device_passes = forest_kernels.n_device_passes
         try:
             outs, n = score_groups(flat, impl=self.impl)
         except Exception as e:
             for req in batch:
                 self._reply(req, {"id": req.req_id, "error": repr(e)})
             return
+        self.n_device_flushes += forest_kernels.n_device_passes > device_passes
         self.n_flushes += 1
         self.n_dispatches += n
         self.n_rows += rows

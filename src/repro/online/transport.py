@@ -358,8 +358,8 @@ class TCPComm(Comm):
             raise CommClosedError(f"undecodable frame: {e!r}") from e
 
     async def close(self):
-        if self._closed:
-            return
+        # a failed send/recv marks the comm closed but leaves the socket
+        # open, so close the writer whatever the flag says (idempotent)
         self._closed = True
         try:
             self._writer.close()
@@ -373,12 +373,17 @@ class TCPComm(Comm):
 
 
 class _TCPListener(Listener):
-    def __init__(self, server, address: str):
+    def __init__(self, server, address: str, comms: set):
         self._server = server
         self.address = address
+        self._comms = comms              # accepted comms still being handled
 
     async def stop(self):
         self._server.close()
+        # wait_closed() waits for every accepted connection to close (Python
+        # 3.12), so the ones whose handlers still run are closed first
+        for comm in list(self._comms):
+            await comm.close()
         await self._server.wait_closed()
 
 
@@ -424,14 +429,22 @@ async def listen(address: str, handler, *, serializer: str = "auto",
         _INPROC[rest] = lst
         return lst
 
+    comms: set = set()
+
     async def on_connect(reader, writer):
-        await handler(TCPComm(reader, writer, serializer=serializer,
-                              max_frame=max_frame))
+        comm = TCPComm(reader, writer, serializer=serializer,
+                       max_frame=max_frame)
+        comms.add(comm)
+        try:
+            await handler(comm)
+        finally:
+            comms.discard(comm)
+            await comm.close()
 
     host, _, port = rest.rpartition(":")
     server = await asyncio.start_server(on_connect, host, int(port))
     bound = server.sockets[0].getsockname()
-    return _TCPListener(server, f"tcp://{bound[0]}:{bound[1]}")
+    return _TCPListener(server, f"tcp://{bound[0]}:{bound[1]}", comms)
 
 
 # ---------------------------------------------------------------------------

@@ -14,10 +14,7 @@ LOGICAL = sorted(DEFAULT_RULES)
 
 
 def _mesh(names=("data", "model")):
-    try:
-        return jax.sharding.AbstractMesh((2,) * len(names), names)
-    except TypeError:   # jax<=0.4.37 signature: tuple of (name, size) pairs
-        return jax.sharding.AbstractMesh(tuple((n, 2) for n in names))
+    return jax.sharding.AbstractMesh((2,) * len(names), names)
 
 
 @settings(max_examples=50, deadline=None)

@@ -105,6 +105,31 @@ def test_grouped_kernel_parity_xla_and_interpret(models):
         assert passes == 1
         for w, o in zip(want, outs):
             np.testing.assert_allclose(o, w, rtol=2e-5, atol=2e-5)
+            if impl == "interpret":      # the Pallas kernel is exact
+                np.testing.assert_array_equal(o, w)
+
+
+@pytest.mark.parametrize("sizes", [
+    (1, 127, 128, 129, 300),             # tile edges inside and across
+    (700, 3, 0, 45, 1),                  # an empty group, tiny segments
+])
+def test_grouped_kernel_interpret_bitwise_heterogeneous(models, sizes):
+    """Heterogeneous padded models (24x5, 8x3, 16x4 and a 1x6 tree packed
+    to 24x6) over uneven segments: the interpreted grouped kernel equals
+    forest_predict_np of each row's own model bit for bit."""
+    X, y = _data(seed=11)
+    tree = fit_oblivious_forest(X, y, n_trees=1, depth=6, n_bins=16,
+                                bootstrap=False)
+    order = [models["a"], models["c"], tree, models["d"], models["a"]]
+    Xq = np.random.RandomState(12).rand(sum(sizes), 12).astype(np.float32)
+    groups, at = [], 0
+    for params, n in zip(order, sizes):
+        groups.append((params, Xq[at:at + n]))
+        at += n
+    outs, passes = forest_predict_grouped(groups, impl="interpret")
+    assert passes == 1
+    for (params, rows), out in zip(groups, outs):
+        np.testing.assert_array_equal(out, forest_predict_np(params, rows))
 
 
 def test_auto_routes_fat_flushes_to_kernel(models):

@@ -91,8 +91,6 @@ def test_xla_cost_analysis_undercounts_loops_demo():
     w = jax.ShapeDtypeStruct((64, 64), jnp.float32)
     compiled = jax.jit(f).lower(x, w).compile()
     ca = compiled.cost_analysis()
-    if isinstance(ca, list):        # older jaxlib returns [dict] per partition
-        ca = ca[0]
     xla_flops = ca["flops"]
     ours = hlo_cost.analyze(compiled.as_text())["flops"]
     assert ours == pytest.approx(10 * xla_flops, rel=0.05)

@@ -12,6 +12,7 @@ from repro.kernels.flash_attention import flash_attention
 from repro.kernels.forest import forest_infer
 from repro.kernels.mamba2_ssd import mamba2_ssd
 from repro.kernels.rwkv6_scan import rwkv6_scan
+from repro.ml.forest import ForestParams, forest_predict_np
 
 
 def _tol(dtype):
@@ -169,6 +170,11 @@ def test_forest_infer(B, F, T, D, bb):
     got = forest_infer(x, feat_idx, thr, leaves, block_b=bb, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-5)
+    # the kernel's arithmetic is the numpy mirror's: bit for bit
+    params = ForestParams(np.asarray(feat_idx), np.asarray(thr),
+                          np.asarray(leaves))
+    np.testing.assert_array_equal(np.asarray(got),
+                                  forest_predict_np(params, np.asarray(x)))
 
 
 def test_forest_infer_vs_sklearn_style_traversal():
@@ -190,6 +196,41 @@ def test_forest_infer_vs_sklearn_style_traversal():
     got = forest_infer(jnp.asarray(x), jnp.asarray(feat_idx, jnp.int32),
                        jnp.asarray(thr), jnp.asarray(leaves), interpret=True)
     np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-5)
+
+
+def test_forest_infer_interpret_bitwise_real_widths():
+    """The predictor's real widths (24 trees x depth 5 over 22 features), a
+    ragged batch, features sitting exactly on thresholds and just off a bf16
+    rounding boundary: the interpreted kernel equals the numpy mirror bit
+    for bit."""
+    rs = np.random.RandomState(5)
+    F, T, D = 22, 24, 5
+    params = ForestParams(rs.randint(0, F, (T, D)).astype(np.int32),
+                          rs.randn(T, D).astype(np.float32),
+                          rs.rand(T, 2 ** D).astype(np.float32))
+    x = (rs.randn(300, F) * 10.0 ** rs.randint(-3, 4, (1, F))) \
+        .astype(np.float32)
+    x[:40, params.feat_idx[0, 0]] = params.thresholds[0, 0]
+    x[40:80] *= np.float32(1 + 2.0 ** -12)
+    got = forest_infer(jnp.asarray(x), jnp.asarray(params.feat_idx),
+                       jnp.asarray(params.thresholds),
+                       jnp.asarray(params.leaves), interpret=True)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  forest_predict_np(params, x))
+
+
+def test_kernel_impl_follows_the_backend():
+    """On the CPU backend ops resolve to the xla reference and the serving
+    brokers score through the numpy mirror; only a TPU selects the kernel."""
+    from repro.kernels import ops
+    from repro.ml.forest import serving_impl
+    assert jax.default_backend() == "cpu"
+    assert ops.default_impl() == "xla"
+    assert ops._resolve(None) == "xla"
+    assert ops._resolve("interpret") == "interpret"
+    assert serving_impl() == "numpy"
+    with pytest.raises(ValueError):
+        ops._resolve("cuda")
 
 
 def test_forest_predict_np_matches_kernel_reference():

@@ -8,7 +8,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.ml.cv import cross_validate, metrics
 from repro.ml.forest import fit_oblivious_forest, forest_predict
-from repro.ml.models import ALL_MODELS
+from repro.ml.models import ALL_MODELS, forest_shape
 
 
 def _synthetic(n=2000, seed=0):
@@ -31,6 +31,19 @@ def test_each_model_beats_majority_class(name):
     acc = (pred == y[1500:]).mean()
     base = max(y[1500:].mean(), 1 - y[1500:].mean())
     assert acc > base + 0.02, f"{name}: acc={acc:.3f} vs majority {base:.3f}"
+
+
+@pytest.mark.parametrize("name,shape", [
+    ("R.F.", (24, 5)), ("Tree", (1, 6)), ("CTree", (1, 6)), ("Boost", None),
+    ("Glm", None),
+])
+def test_forest_shape_is_the_fitted_block_shape(name, shape):
+    """forest_shape gives the (trees, depth) block a single-forest model
+    brings to a grouped flush, and None for every other algo."""
+    assert forest_shape(name) == shape
+    if shape is not None:
+        X, y = _synthetic(n=300)
+        assert ALL_MODELS[name]().fit(X, y).params.feat_idx.shape == shape
 
 
 def test_random_forest_best_or_near_best():

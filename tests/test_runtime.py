@@ -100,9 +100,8 @@ def test_error_feedback_mean_converges():
 def test_compressed_psum_single_device():
     g = jnp.ones((300,)) * 0.5
     mesh = jax.make_mesh((1,), ("x",))
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
-    out, resid = shard_map(
+    out, resid = jax.shard_map(
         lambda g: compressed_psum(g, "x"), mesh=mesh,
         in_specs=(P(),), out_specs=(P(), P()))(g)
     np.testing.assert_allclose(np.asarray(out), 0.5, rtol=1e-2)

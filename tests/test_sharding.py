@@ -21,10 +21,7 @@ def _mesh22():
     devices, so these run on a single-device host too."""
     if jax.device_count() >= 4:
         return jax.make_mesh((2, 2), ("data", "model"))
-    try:
-        return jax.sharding.AbstractMesh((2, 2), ("data", "model"))
-    except TypeError:   # jax<=0.4.37 signature: tuple of (name, size) pairs
-        return jax.sharding.AbstractMesh((("data", 2), ("model", 2)))
+    return jax.sharding.AbstractMesh((2, 2), ("data", "model"))
 
 
 def test_rules_resolution_basics():
