@@ -42,6 +42,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.obs.spans import stage
+
 
 def _tile_sums(x, sel, thr, leaves_ref, n_trees, *, T: int, D: int):
     """Tree-order leaf sums for one batch tile, rows along lanes: (1, Bb).
@@ -181,6 +183,7 @@ def forest_infer_grouped(x, seg_sizes, feat_idx, thresholds, leaves, n_trees,
     n_trees: (M,) true tree counts.  Returns (R,) mean-leaf scores where each
     row is scored only by its own model's trees."""
     global n_device_passes
+    stage("forest.operands")
     x = np.asarray(x, np.float32)
     R, F = x.shape
     D = np.shape(thresholds)[2]
@@ -189,8 +192,12 @@ def forest_infer_grouped(x, seg_sizes, feat_idx, thresholds, leaves, n_trees,
     for dst, src, rows in spans:
         xp[dst:dst + rows] = x[src:src + rows]
     sel, thr, lv, nt = grouped_blocks(feat_idx, thresholds, leaves, n_trees, F)
-    out = np.asarray(grouped_call(seg_of_tile, nt, xp, sel, thr, lv, D=D,
-                                  block_b=block_b, interpret=interpret))
+    stage("forest.launch")
+    out = grouped_call(seg_of_tile, nt, xp, sel, thr, lv, D=D,
+                       block_b=block_b, interpret=interpret)
+    stage("forest.fetch")
+    out = np.asarray(out)
+    stage("forest.unpack")
     n_device_passes += not interpret
     scores = np.empty(R, np.float32)
     for (dst, src, rows), n in zip(spans, nt):

@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.spans import stage
+
 
 @dataclasses.dataclass
 class ForestParams:
@@ -405,6 +407,7 @@ def forest_predict_grouped(groups, *, impl: str = "numpy") -> tuple[list, int]:
                 outs[i] = scores[span[0]:span[1]]
         return outs, 1
 
+    stage("forest.numpy")
     if len(order) == 1:
         # single model: the existing numpy mirror (shared tree block over the
         # stacked rows) — same arithmetic, no per-row index plumbing
@@ -423,6 +426,7 @@ def forest_predict_grouped(groups, *, impl: str = "numpy") -> tuple[list, int]:
             # fixed-order mean over the model's TRUE tree count: the padded
             # tail never enters the accumulation
             means[pid] = _mean_over_trees(votes[s:s + counts[pid], :t])
+    stage("forest.unpack")
     for pid in order:
         s = seg_start[pid]
         block = means[pid]

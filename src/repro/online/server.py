@@ -81,6 +81,8 @@ import time
 import numpy as np
 
 from repro.kernels import forest as forest_kernels
+from repro.obs import spans
+from repro.obs.metrics import WAIT_EDGES_S, MetricsRegistry, Pending
 from repro.online.broker import score_groups
 from repro.online.faults import (FaultInjector, PredictorUnavailableError,
                                  backoff_delay)
@@ -90,15 +92,23 @@ from repro.online.transport import (CommClosedError, SyncComm, connect,
 _SERVE_SEQ = itertools.count()
 _CLIENT_SEQ = itertools.count()
 
+# the serving path's wait histograms (seconds; reporting only, like the
+# cause counters): every broker clones this schema
+_TIMING = MetricsRegistry(ring_capacity=1)
+H_HOP_IN = _TIMING.histogram("hop_in", WAIT_EDGES_S)
+H_HOP_OUT = _TIMING.histogram("hop_out", WAIT_EDGES_S)
+H_QUEUE_WAIT = _TIMING.histogram("queue_wait", WAIT_EDGES_S)
+_TIMING.freeze()
+
 
 class _Req:
     """One admitted request: where to reply + its span of the next flush."""
 
     __slots__ = ("comm", "req_id", "groups", "rows", "vadmit", "deadline",
-                 "client")
+                 "client", "t_admit")
 
     def __init__(self, comm, req_id, groups, rows, vadmit, deadline,
-                 client=None):
+                 client=None, t_admit=None):
         self.comm = comm
         self.req_id = req_id
         self.groups = groups
@@ -106,6 +116,7 @@ class _Req:
         self.vadmit = vadmit
         self.deadline = deadline
         self.client = client
+        self.t_admit = time.perf_counter() if t_admit is None else t_admit
 
 
 class AsyncBroker:
@@ -177,6 +188,12 @@ class AsyncBroker:
         self.n_replays = 0               # cached replies resent to retries
         self.n_dup_requests = 0          # retransmits of in-flight requests
         self.n_device_flushes = 0        # flushes scored by the device kernel
+        # wait histograms (reporting only — wall clock): timing_stats()
+        self.timing = _TIMING.clone()
+        self._hop_in = Pending(self.timing, H_HOP_IN)
+        self._hop_out = Pending(self.timing, H_HOP_OUT)
+        self._queue_wait = Pending(self.timing, H_QUEUE_WAIT)
+        self._queued = None              # the open broker.queued span
 
     # ------------------------------------------------------------ lifecycle
     def start(self) -> "AsyncBroker":
@@ -219,6 +236,7 @@ class AsyncBroker:
         if not address:
             address = f"inproc://broker-{next(_SERVE_SEQ)}"
         kw.setdefault("serializer", self.serializer)
+        kw.setdefault("waits", (self._hop_in, self._hop_out))
         handler = self._handle
         injector = None
         if fault_plan is not None:
@@ -319,6 +337,22 @@ class AsyncBroker:
         return {"replays": self.n_replays,
                 "dup_requests": self.n_dup_requests,
                 "injected": injected}
+
+    def timing_stats(self) -> dict:
+        """Cumulative wait histograms over ``edges_s`` (upper bucket edges in
+        seconds; one more bucket than edges, the last counting waits past
+        them): ``hop_in`` each inproc message's wait in its channel from a
+        client to the broker, on every listener of the broker; ``hop_out``
+        the same from the broker to a client; ``queue_wait`` each scored
+        request's admission to the start of its flush.  Reporting only —
+        wall-clock values, so they stay out of ``stats()``."""
+        for waits in (self._hop_in, self._hop_out, self._queue_wait):
+            waits.fold()
+        counts = self.timing.hist_counts
+        return {"edges_s": WAIT_EDGES_S.tolist(),
+                "hop_in": list(counts[H_HOP_IN]),
+                "hop_out": list(counts[H_HOP_OUT]),
+                "queue_wait": list(counts[H_QUEUE_WAIT])}
 
     # ------------------------------------------------------------ membership
     def add_clients(self, n: int = 1):
@@ -439,14 +473,18 @@ class AsyncBroker:
                 await self._drain.wait()
         self.n_requests += 1
         budget = msg.get("budget_ms", self.slo_ms)
-        deadline = (time.perf_counter() + budget * 1e-3 * self.slo_margin
-                    if budget else None)
+        now = time.perf_counter()
+        deadline = now + budget * 1e-3 * self.slo_margin if budget else None
         self._vnow += 1
         req = _Req(comm, msg.get("id"), groups, rows, self._vnow, deadline,
-                   msg.get("client"))
+                   msg.get("client"), now)
         if req.client is not None:
             self._replay[req.client] = (req.req_id, "pending", req)
         first = not self._queue
+        if first:
+            # the next flush is the one that takes this request
+            self._queued = spans.span("broker.queued", flush=self._epoch + 1)
+            self._queued.__enter__()
         self._queue.append(req)
         self._queued_rows += rows
         if self.policy == "barrier":
@@ -492,36 +530,50 @@ class AsyncBroker:
             self._flush("slo")
 
     def _flush(self, cause: str):
-        batch, self._queue = self._queue, []
-        rows, self._queued_rows = self._queued_rows, 0
-        self._epoch += 1
-        if self._slo_handle is not None:
-            self._slo_handle.cancel()
-            self._slo_handle = None
-            self._slo_at = float("inf")
-        self._drain.set()
-        flat = [g for req in batch for g in req.groups]
-        t0 = time.perf_counter()
-        device_passes = forest_kernels.n_device_passes
-        try:
-            outs, n = score_groups(flat, impl=self.impl)
-        except Exception as e:
+        # stage spans tile the flush (repro.obs.spans): broker.pack, the
+        # scoring pass's stages, broker.reply; broker.queued ends here
+        if self._queued is not None:
+            self._queued.__exit__(None, None, None)
+            self._queued = None
+        with spans.Stages("broker.pack", flush=self._epoch + 1) as stages:
+            t_start = time.perf_counter()
+            batch, self._queue = self._queue, []
+            rows, self._queued_rows = self._queued_rows, 0
+            self._epoch += 1
+            if self._slo_handle is not None:
+                self._slo_handle.cancel()
+                self._slo_handle = None
+                self._slo_at = float("inf")
+            self._drain.set()
+            waited = self._queue_wait.values
+            waited.extend([t_start - r.t_admit for r in batch])
+            if len(waited) >= Pending.FOLD_AT:
+                self._queue_wait.fold()
+            flat = [g for req in batch for g in req.groups]
+            t0 = time.perf_counter()
+            device_passes = forest_kernels.n_device_passes
+            try:
+                outs, n = score_groups(flat, impl=self.impl)
+            except Exception as e:
+                stages.to("broker.reply")
+                for req in batch:
+                    self._reply(req, {"id": req.req_id, "error": repr(e)})
+                return
+            self.n_device_flushes += \
+                forest_kernels.n_device_passes > device_passes
+            self.n_flushes += 1
+            self.n_dispatches += n
+            self.n_rows += rows
+            self.max_flush_rows = max(self.max_flush_rows, rows)
+            if self.obs is not None:
+                self.obs.record_flush(rows, len(batch), n,
+                                      time.perf_counter() - t0)
+            stages.to("broker.reply")
+            at = 0
             for req in batch:
-                self._reply(req, {"id": req.req_id, "error": repr(e)})
-            return
-        self.n_device_flushes += forest_kernels.n_device_passes > device_passes
-        self.n_flushes += 1
-        self.n_dispatches += n
-        self.n_rows += rows
-        self.max_flush_rows = max(self.max_flush_rows, rows)
-        if self.obs is not None:
-            self.obs.record_flush(rows, len(batch), n,
-                                  time.perf_counter() - t0)
-        at = 0
-        for req in batch:
-            span = outs[at:at + len(req.groups)]
-            at += len(req.groups)
-            self._reply(req, {"id": req.req_id, "probs": span})
+                span = outs[at:at + len(req.groups)]
+                at += len(req.groups)
+                self._reply(req, {"id": req.req_id, "probs": span})
 
     def _reply(self, req: _Req, msg: dict):
         if req.client is not None:

@@ -47,6 +47,7 @@ import collections
 import concurrent.futures
 import json
 import struct
+from time import perf_counter
 
 import numpy as np
 
@@ -198,11 +199,19 @@ class _Channel:
 
     ``asyncio.Event`` pairs signal data-available / space-available; a full
     channel parks the sender until the consumer drains (bounded-queue
-    backpressure with zero copies — the object itself is the payload)."""
+    backpressure with zero copies — the object itself is the payload).
 
-    def __init__(self, capacity: int):
+    ``waits`` is an optional ``repro.obs.metrics.Pending`` of a wait
+    histogram: each message is stamped when it enters, and the reader adds
+    the seconds it sat in the channel there."""
+
+    def __init__(self, capacity: int, waits=None):
         self.q: collections.deque = collections.deque()
         self.capacity = capacity
+        self.waits = waits
+        # the hot path's handles on the buffer (``fold`` empties it in place)
+        self.waited = None if waits is None else waits.values
+        self.fold_at = None if waits is None else waits.FOLD_AT
         self.readable = asyncio.Event()
         self.writable = asyncio.Event()
         self.writable.set()
@@ -214,7 +223,7 @@ class _Channel:
             await self.writable.wait()
         if self.closed:
             raise CommClosedError("inproc peer closed")
-        self.q.append(msg)
+        self.q.append((msg, perf_counter()))
         self.readable.set()
 
     async def get(self):
@@ -223,7 +232,12 @@ class _Channel:
                 raise CommClosedError("inproc peer closed")
             self.readable.clear()
             await self.readable.wait()
-        msg = self.q.popleft()
+        msg, t_put = self.q.popleft()
+        waited = self.waited
+        if waited is not None:
+            waited.append(perf_counter() - t_put)
+            if len(waited) >= self.fold_at:
+                self.waits.fold()
         if len(self.q) < self.capacity:
             self.writable.set()
         return msg
@@ -261,15 +275,18 @@ class InProcComm(Comm):
 
 
 class _InProcListener(Listener):
-    def __init__(self, name: str, handler, capacity: int):
+    def __init__(self, name: str, handler, capacity: int, waits=None):
         self.address = f"inproc://{name}"
         self._name = name
         self._handler = handler
         self._capacity = capacity
+        self._waits = waits
         self._tasks: set = set()
 
     def _connect(self) -> InProcComm:
-        a, b = _Channel(self._capacity), _Channel(self._capacity)
+        hop_in, hop_out = self._waits or (None, None)
+        a = _Channel(self._capacity, hop_in)      # client -> listener
+        b = _Channel(self._capacity, hop_out)     # listener -> client
         server_side = InProcComm(a, b, self.address, "inproc://client")
         client_side = InProcComm(b, a, "inproc://client", self.address)
         t = asyncio.ensure_future(self._handler(server_side))
@@ -416,16 +433,20 @@ async def connect(address: str, *, serializer: str = "auto",
 
 async def listen(address: str, handler, *, serializer: str = "auto",
                  max_frame: int = DEFAULT_MAX_FRAME,
-                 capacity: int = 1024) -> Listener:
+                 capacity: int = 1024, waits=None) -> Listener:
     """Bind ``address`` and invoke ``await handler(comm)`` per connection.
 
     ``tcp://host:0`` binds an ephemeral port; read the bound address back
-    from ``listener.address``."""
+    from ``listener.address``.  ``waits`` is an optional pair of
+    ``repro.obs.metrics.Pending`` wait histograms, ``(hop_in, hop_out)``:
+    an inproc listener adds to them the seconds each message sat in its
+    channel, client to listener and listener to client.  TCP records
+    nothing."""
     scheme, rest = parse_address(address)
     if scheme == "inproc":
         if rest in _INPROC:
             raise ValueError(f"inproc listener {address!r} already bound")
-        lst = _InProcListener(rest, handler, capacity)
+        lst = _InProcListener(rest, handler, capacity, waits)
         _INPROC[rest] = lst
         return lst
 
