@@ -14,6 +14,9 @@ the profiler, then reads:
   ``hop_out``;
 - the median decision against send lag + hop_in + queue_wait + flush +
   reply + hop_out, and what is left over;
+- the window's share of replies the broker put in their channels inside
+  the flush (``n_direct_replies``) rather than by an asyncio Task
+  (``n_task_replies``), which ``hop_out`` follows;
 - the benchmark's breakdown of the device's idle gaps, each named after the
   host span that covers most of it (``bench.trace_reduce``).
 
@@ -108,22 +111,38 @@ def decompose(win: dict, events: dict, timing0: dict, timing1: dict,
             "idle_gaps": None if tr is None else tr["idle_gaps"]}
 
 
+def reply_counts(broker) -> dict:
+    """The broker's replies so far: put directly, and sent by a Task."""
+    return {"direct": broker.n_direct_replies, "task": broker.n_task_replies}
+
+
+def direct_share(replies0: dict, replies1: dict) -> float | None:
+    """The share of a window's replies that went in directly, from
+    ``reply_counts`` at its start and end; ``None`` when it had none."""
+    direct = replies1["direct"] - replies0["direct"]
+    total = direct + replies1["task"] - replies0["task"]
+    return direct / total if total else None
+
+
 def run(workload: str, seed: int, seconds: float,
         root=harness.ROOT) -> dict:
     """One traced window of ``workload``, split by ``decompose``."""
     _, _, config, traffic, device, driver = harness.setup(workload, root)
     sut = driver.ServeCell(config, traffic, seed)
     timing0: dict = {}
+    replies0: dict = {}
     try:
         sut.start()
         with MachineWitness() as witness, \
                 harness.profiled(True) as (start_trace, traced):
             def on_start():              # the waits' counts, then the trace
                 timing0.update(sut.broker.timing_stats())
+                replies0.update(reply_counts(sut.broker))
                 start_trace()
 
             win = sut.window(seconds, on_start=on_start)
             timing1 = sut.broker.timing_stats()
+            replies1 = reply_counts(sut.broker)
     finally:
         sut.stop()
     checks = driver.check(win, sut)
@@ -136,6 +155,8 @@ def run(workload: str, seed: int, seconds: float,
                           for k in driver.LIMITS),
            "machine_pauses": harness.pause_summary(pauses)}
     out.update(decompose(win, events, timing0, timing1, driver))
+    out["direct_reply_share"] = direct_share(replies0, replies1)
+    out["replies"] = {k: replies1[k] - replies0[k] for k in replies1}
     return out
 
 

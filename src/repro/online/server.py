@@ -73,6 +73,7 @@ from __future__ import annotations
 
 import asyncio
 import concurrent.futures
+import functools
 import itertools
 import os
 import threading
@@ -126,7 +127,18 @@ class AsyncBroker:
     named-model ``predict`` op (the only op that works across tcp://);
     in-process clients may instead ship live model objects via ``submit``.
     The loop runs on a dedicated daemon thread (``start``/``stop``);
-    ``serve`` binds any number of transport addresses onto it."""
+    ``serve`` binds any number of transport addresses onto it.
+
+    Replies leave inside the flush that scored them: each goes out by the
+    comm's ``send_nowait``, so an inproc reply is in its client's channel,
+    and the reader's wake-up queued, before the flush returns and ahead of
+    the next flush that later admissions schedule.  Where a comm cannot
+    send without suspending (TCP, a fault-wrapped comm, a comm with no
+    ``send_nowait``, a full channel), the reply goes by an asyncio Task
+    that awaits ``send``; while such a Task is in flight, later replies to
+    the same comm take a Task too, so each comm's replies keep their order.
+    ``n_direct_replies`` and ``n_task_replies`` count the two ways
+    (reporting only, like the cause counters)."""
 
     def __init__(self, models: dict | None = None, *, impl: str = "numpy",
                  policy: str = "vt", depth: int = 2048,
@@ -188,6 +200,9 @@ class AsyncBroker:
         self.n_replays = 0               # cached replies resent to retries
         self.n_dup_requests = 0          # retransmits of in-flight requests
         self.n_device_flushes = 0        # flushes scored by the device kernel
+        self.n_direct_replies = 0        # replies put by send_nowait
+        self.n_task_replies = 0          # replies sent by an asyncio Task
+        self._sending: dict = {}         # comm -> its last Task reply in flight
         # wait histograms (reporting only — wall clock): timing_stats()
         self.timing = _TIMING.clone()
         self._hop_in = Pending(self.timing, H_HOP_IN)
@@ -442,14 +457,8 @@ class AsyncBroker:
             val.comm = comm              # reply lands on the fresh comm
         else:
             self.n_replays += 1
-            self._send_cached(comm, val)
+            self._send_reply(comm, val)
         return True
-
-    def _send_cached(self, comm, msg: dict):
-        if comm.closed:
-            return
-        task = asyncio.ensure_future(comm.send(msg))
-        task.add_done_callback(_swallow_closed)
 
     async def _admit(self, comm, msg, op):
         if op == "predict":
@@ -580,10 +589,35 @@ class AsyncBroker:
             # cache even error replies: scoring is deterministic, so a retry
             # of a failed request deserves the same verdict, not a rescore
             self._replay[req.client] = (req.req_id, "done", msg)
-        if req.comm.closed:
+        self._send_reply(req.comm, msg)
+
+    def _send_reply(self, comm, msg: dict):
+        """Send a reply without suspending where ``comm`` can, else by a
+        Task (the class docstring).  A closed comm drops it."""
+        if comm.closed:
             return
-        task = asyncio.ensure_future(req.comm.send(msg))
-        task.add_done_callback(_swallow_closed)
+        if comm not in self._sending:
+            send_nowait = getattr(comm, "send_nowait", None)
+            try:
+                if send_nowait is not None and send_nowait(msg):
+                    self.n_direct_replies += 1
+                    return
+            except CommClosedError:
+                return                   # the peer left: nobody to tell
+        self.n_task_replies += 1
+        task = asyncio.ensure_future(comm.send(msg))
+        self._sending[comm] = task
+        task.add_done_callback(functools.partial(self._task_sent, comm))
+
+    def _task_sent(self, comm, task: asyncio.Task):
+        """A Task reply ended; one that raced a client disconnect has
+        nobody to tell."""
+        if self._sending.get(comm) is task:
+            del self._sending[comm]
+        if not task.cancelled():
+            exc = task.exception()
+            if exc is not None and not isinstance(exc, CommClosedError):
+                raise exc
 
     # ------------------------------------------------------------ telemetry
     def _route_telemetry(self, msg: dict):
@@ -642,14 +676,6 @@ class AsyncBroker:
                 "rows": self.n_rows, "requests": self.n_requests,
                 "max_flush_rows": self.max_flush_rows,
                 "policy": self.policy}
-
-
-def _swallow_closed(task: asyncio.Task):
-    """A reply raced a client disconnect: nothing to do, nobody to tell."""
-    if not task.cancelled():
-        exc = task.exception()
-        if exc is not None and not isinstance(exc, CommClosedError):
-            raise exc
 
 
 class BrokerClient:

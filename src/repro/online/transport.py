@@ -14,7 +14,7 @@ dask.distributed's comm core: an address string picks a backend,
                         JSON otherwise (numpy arrays round-trip losslessly in
                         both — raw bytes under msgpack, base64 under JSON)
 
-and every backend hands back the same five-method ``Comm``:
+and every backend hands back the same ``Comm``:
 
     comm = await connect("tcp://127.0.0.1:9815")
     await comm.send({"op": "predict", "kind": "map", "X": rows})
@@ -23,6 +23,15 @@ and every backend hands back the same five-method ``Comm``:
 
     listener = await listen("inproc://broker", handler)   # handler(comm)
     await listener.stop()
+
+A comm also has one plain method, ``comm.send_nowait(msg) -> bool``: send
+without suspending where the backend can.  An inproc comm enqueues the
+message at once when its channel has room and returns ``True``; ``False``
+(a full channel, or a backend that cannot send without awaiting, TCP among
+them) means nothing was sent and the caller falls back to ``await send``.
+It raises ``CommClosedError`` where ``send`` would.  A caller on the loop
+(the broker's flush) uses it to put replies in their channels before it
+returns, so a reader wakes ahead of whatever the loop schedules next.
 
 Failure semantics are explicit and tested: ``recv()`` on a peer-closed comm
 raises ``CommClosedError`` (a clean EOF between frames) and a connection cut
@@ -165,6 +174,12 @@ class Comm:
     async def send(self, msg) -> None:
         raise NotImplementedError
 
+    def send_nowait(self, msg) -> bool:
+        """Send ``msg`` without suspending, if this comm can: ``True`` when
+        it was sent, ``False`` when nothing was sent and the caller must
+        ``await send(msg)`` instead.  The default cannot."""
+        return False
+
     async def recv(self):
         raise NotImplementedError
 
@@ -201,6 +216,10 @@ class _Channel:
     channel parks the sender until the consumer drains (bounded-queue
     backpressure with zero copies — the object itself is the payload).
 
+    ``put_nowait`` is the non-suspending half of ``put``: it enqueues and
+    returns ``True`` below capacity, and returns ``False`` on a full
+    channel, where ``put`` would park.
+
     ``waits`` is an optional ``repro.obs.metrics.Pending`` of a wait
     histogram: each message is stamped when it enters, and the reader adds
     the seconds it sat in the channel there."""
@@ -217,14 +236,19 @@ class _Channel:
         self.writable.set()
         self.closed = False
 
-    async def put(self, msg):
-        while len(self.q) >= self.capacity and not self.closed:
-            self.writable.clear()
-            await self.writable.wait()
+    def put_nowait(self, msg) -> bool:
         if self.closed:
             raise CommClosedError("inproc peer closed")
+        if len(self.q) >= self.capacity:
+            return False
         self.q.append((msg, perf_counter()))
         self.readable.set()
+        return True
+
+    async def put(self, msg):
+        while not self.put_nowait(msg):
+            self.writable.clear()
+            await self.writable.wait()
 
     async def get(self):
         while not self.q:
@@ -249,6 +273,10 @@ class _Channel:
 
 
 class InProcComm(Comm):
+    """One end of an inproc connection: reads ``rx``, writes ``tx``.
+    ``send_nowait`` enqueues on ``tx`` when it has room (``True``) and
+    returns ``False`` on a full ``tx``."""
+
     def __init__(self, rx: _Channel, tx: _Channel, local: str, peer: str):
         self._rx, self._tx = rx, tx
         self.local_addr, self.peer_addr = local, peer
@@ -258,6 +286,11 @@ class InProcComm(Comm):
         if self._closed:
             raise CommClosedError("comm already closed")
         await self._tx.put(msg)
+
+    def send_nowait(self, msg) -> bool:
+        if self._closed:
+            raise CommClosedError("comm already closed")
+        return self._tx.put_nowait(msg)
 
     async def recv(self):
         if self._closed:

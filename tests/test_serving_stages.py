@@ -186,6 +186,13 @@ def test_decompose_splits_a_decision_into_its_parts():
     assert got["idle_gaps"] is None                     # no device events
 
 
+def test_direct_share_reads_the_window_deltas():
+    r0 = {"direct": 10, "task": 4}
+    assert serving_stages.direct_share(
+        r0, {"direct": 37, "task": 7}) == pytest.approx(27 / 30)
+    assert serving_stages.direct_share(r0, dict(r0)) is None
+
+
 def _smoke_root(tmp_path):
     """A checkout's benchmark files holding one small serve cell."""
     root = harness.ROOT
@@ -231,3 +238,6 @@ def test_traced_smoke_cell_is_split_into_stages(tmp_path, monkeypatch):
     assert all(v is not None and v >= 0
                for v in out["decision_p50_parts_ms"].values())
     assert out["queued_s"] > 0
+    # every reply went into its channel inside the flush
+    assert out["replies"] == {"direct": n, "task": 0}
+    assert out["direct_reply_share"] == 1.0
